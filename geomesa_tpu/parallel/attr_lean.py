@@ -35,10 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..index.attr_lean import (
     _SENTINEL_KEY, _HostAttrStack, _I64_MAX, _I64_MIN, SLOT_BYTES,
@@ -50,7 +47,7 @@ from ..obs.heat import (
     heat_enabled, merge_index_generations, record_index_scan,
 )
 from ..ops.search import (
-    expand_ranges, gather_capacity, pad_pow2, searchsorted2,
+    expand_ranges, gather_capacity, pad_pow2, searchsorted2, sort_lex2,
 )
 from .scan import _fetch_global, encode_gids
 from ..index.xz2_lean import (
@@ -84,7 +81,7 @@ def _append_program(mesh: Mesh):
         k0 = jax.lax.dynamic_update_slice(k0, k_new, (r[0, 0],))
         s0 = jax.lax.dynamic_update_slice(s0, s_new, (r[0, 0],))
         g0 = jax.lax.dynamic_update_slice(g0, g_new, (r[0, 0],))
-        k0, s0, g0 = jax.lax.sort((k0, s0, g0), dimension=0, num_keys=2)
+        k0, s0, g0 = sort_lex2(k0, s0, g0)
         return k0[None], s0[None], g0[None]
 
     return jax.jit(app, donate_argnums=(0, 1, 2))

@@ -38,10 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..ops.search import (
     expand_ranges, gather_capacity, pad_pow2, pad_ranges, searchsorted2,

@@ -21,7 +21,7 @@ HBM budget):
   bbox+time mask runs fused INSIDE the shard_map scan and only true
   hits leave the device.  Unlike the single-chip full tier (payload in
   append order, gathered by ``pos - base``), the sharded payload is
-  carried THROUGH the per-shard sort as extra ``lax.sort`` operands:
+  carried THROUGH the per-shard sort (gathered by its permutation):
   a shard's rows are block-split slices of many appends, so gids are
   not generation-contiguous per shard and a ``pos - base`` gather
   cannot work — sorted payload lets the expand index it directly.
@@ -50,10 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..curve.binnedtime import TimePeriod, to_binned_time
 from ..index.z3 import Z3_INDEX_VERSION, plan_z3_query, z3_sfc_for_version
@@ -65,7 +62,7 @@ from ..obs.heat import (
 )
 from ..ops.search import (
     expand_ranges, gather_capacity, pad_boxes, pad_pow2, pad_ranges,
-    searchsorted2,
+    searchsorted2, sort_lex2,
 )
 from .scan import _fetch_global, encode_gids
 
@@ -113,7 +110,7 @@ def _append_program(mesh: Mesh, sfc):
         b0 = jax.lax.dynamic_update_slice(b0, b_new, (r,))
         z0 = jax.lax.dynamic_update_slice(z0, z_new, (r,))
         p0 = jax.lax.dynamic_update_slice(p0, p_new, (r,))
-        b0, z0, p0 = jax.lax.sort((b0, z0, p0), dimension=0, num_keys=2)
+        b0, z0, p0 = sort_lex2(b0, z0, p0)
         return b0[None], z0[None], p0[None]
 
     return jax.jit(app, donate_argnums=(0, 1, 2))
@@ -144,8 +141,7 @@ def _append_program_full(mesh: Mesh, sfc):
         x0 = jax.lax.dynamic_update_slice(x0, xs[0], (r,))
         y0 = jax.lax.dynamic_update_slice(y0, ys[0], (r,))
         t0 = jax.lax.dynamic_update_slice(t0, ts[0], (r,))
-        b0, z0, p0, x0, y0, t0 = jax.lax.sort(
-            (b0, z0, p0, x0, y0, t0), dimension=0, num_keys=2)
+        b0, z0, p0, x0, y0, t0 = sort_lex2(b0, z0, p0, x0, y0, t0)
         return (b0[None], z0[None], p0[None], x0[None], y0[None],
                 t0[None])
 
@@ -169,7 +165,7 @@ def _merge_program(mesh: Mesh, n_gens: int, out_slots: int):
         b = jnp.concatenate([cols[3 * i][0] for i in range(n_gens)])
         z = jnp.concatenate([cols[3 * i + 1][0] for i in range(n_gens)])
         p = jnp.concatenate([cols[3 * i + 2][0] for i in range(n_gens)])
-        b, z, p = jax.lax.sort((b, z, p), dimension=0, num_keys=2)
+        b, z, p = sort_lex2(b, z, p)
         return (b[None, :out_slots], z[None, :out_slots],
                 p[None, :out_slots])
 

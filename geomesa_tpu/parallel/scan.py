@@ -35,15 +35,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax>=0.8 top-level API; the experimental path is deprecated
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..curve.binnedtime import TimePeriod, to_binned_time
 from ..curve.sfc import z3_sfc
 from ..index.z3 import candidate_mask, plan_z3_query
-from ..ops.density import density_grid, density_grid_auto
+from ..ops.density import density_grid_auto
 from ..ops.search import (
     expand_ranges, gather_capacity, pad_boxes, pad_pow2, pad_ranges,
     searchsorted2,
@@ -1094,39 +1091,28 @@ def sharded_density(mesh, x, y, dtg, gid, weights, boxes,
     if weights is not None and bases is None:
         bases = jnp.zeros((1,), jnp.int64)
 
-    def make(dens_grid):
-        specs = [P("shard")] * 4 + [P(None)]
-        if weights is not None:
-            specs += [P(None), P(None)]
+    specs = [P("shard")] * 4 + [P(None)]
+    if weights is not None:
+        specs += [P(None), P(None)]
 
-        @partial(shard_map, mesh=mesh,
-                 in_specs=tuple(specs), out_specs=P(None, None))
-        def dens(xs, ys, ts, gs, bx, *wt):
-            in_box = (
-                (xs[:, None] >= bx[None, :, 0])
-                & (ys[:, None] >= bx[None, :, 1])
-                & (xs[:, None] <= bx[None, :, 2])
-                & (ys[:, None] <= bx[None, :, 3])
-            ).any(axis=1)
-            mask = (gs >= 0) & in_box & (ts >= t_lo_ms) & (ts <= t_hi_ms)
-            if wt:
-                ws = gid_weight_lookup(gs, wt[0], wt[1])
-            else:
-                ws = jnp.ones_like(xs)
-            grid = dens_grid(xs, ys, ws, mask, env, width, height)
-            return jax.lax.psum(grid, "shard")
+    @partial(shard_map, mesh=mesh,
+             in_specs=tuple(specs), out_specs=P(None, None))
+    def dens(xs, ys, ts, gs, bx, *wt):
+        in_box = (
+            (xs[:, None] >= bx[None, :, 0])
+            & (ys[:, None] >= bx[None, :, 1])
+            & (xs[:, None] <= bx[None, :, 2])
+            & (ys[:, None] <= bx[None, :, 3])
+        ).any(axis=1)
+        mask = (gs >= 0) & in_box & (ts >= t_lo_ms) & (ts <= t_hi_ms)
+        if wt:
+            ws = gid_weight_lookup(gs, wt[0], wt[1])
+        else:
+            ws = jnp.ones_like(xs)
+        # pallas histogram on TPU (a Mosaic failure raises), XLA elsewhere
+        grid = density_grid_auto(xs, ys, ws, mask, env, width, height)
+        return jax.lax.psum(grid, "shard")
 
-        args = (x, y, dtg, gid, boxes) + (
-            (weights, bases) if weights is not None else ())
-        return np.asarray(jax.jit(dens)(*args))
-
-    from ..ops.pallas_kernels import on_tpu
-
-    if on_tpu():
-        # pallas histogram under shard_map; fall back if lowering fails
-        try:
-            return make(density_grid_auto)
-        except Exception:
-            from ..metrics import registry as _metrics
-            _metrics.counter("pallas.density.fallback").inc()
-    return make(density_grid)
+    args = (x, y, dtg, gid, boxes) + (
+        (weights, bases) if weights is not None else ())
+    return np.asarray(jax.jit(dens)(*args))

@@ -24,10 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..stats.stat import Stat, parse_stat
 
@@ -99,18 +96,6 @@ def _bbox_time_mask(xs, ys, ts, gs, bx, t_lo, t_hi):
         & (ys[:, None] <= bx[None, :, 3])
     ).any(axis=1)
     return (gs >= 0) & in_box & (ts >= t_lo) & (ts <= t_hi)
-
-
-def _hist1d_probe():
-    """Tiny STANDALONE hist1d kernel call (no collectives): the gate's
-    multihost probe — a divergent Mosaic lowering failure must surface
-    before any process enters the collective program (pallas_kernels.
-    PallasGate._agree_multihost)."""
-    from ..ops.pallas_kernels import hist1d_pallas
-    # gm-lint: disable=host-sync one-shot lowering probe at gate init, not a query path
-    np.asarray(hist1d_pallas(jnp.zeros(8, jnp.int32),
-                             jnp.ones(8, jnp.float32),
-                             jnp.ones(8, bool), 8))
 
 
 def _hist_pallas_ok(idx) -> bool:
@@ -226,8 +211,7 @@ def sharded_stats_scan(idx, boxes, t_lo_ms, t_hi_ms, values=None,
             np.asarray(out[5]),)
 
     cnt, s, s2, vmin, vmax, hist = gate.run(
-        lambda: _run(True), lambda: _run(False), enabled=use_pallas,
-        probe=_hist1d_probe)
+        lambda: _run(True), lambda: _run(False), enabled=use_pallas)
     # host reduce of the per-shard partials (n_shards scalars each)
     res = {"count": int(cnt.sum()), "sum": float(s.sum()),
            "sumsq": float(s2.sum()),
@@ -325,7 +309,7 @@ def sharded_frequency_scan(idx, boxes, t_lo_ms, t_hi_ms, values,
 
     out = GATES["hist1d"].run(
         lambda: _run(True), lambda: _run(False),
-        enabled=_hist_pallas_ok(idx), probe=_hist1d_probe)
+        enabled=_hist_pallas_ok(idx))
     return Frequency("", int(depth), int(width),
                      out.astype(np.int64))
 

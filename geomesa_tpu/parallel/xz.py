@@ -21,10 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..curve.binnedtime import TimePeriod, to_binned_time
 from ..curve.xz2 import xz2_sfc

@@ -88,7 +88,7 @@ def knn_process(store, schema: str, x: float, y: float, k: int,
         return positions, d, order
 
     # batched expanding rings: each dispatch scans THREE radii at once
-    # (r, 2r, 4r) so the remote round trip amortizes across rounds — the
+    # (r, 2r, 4r) so one dispatch and host sync serve three rounds — the
     # GeoHash-spiral expansion (process/knn/KNNQuery.scala:34-101)
     # re-expressed as indexed window batches
     while True:

@@ -55,16 +55,6 @@ def run(n: int = 200_000_000, slice_rows: int = 4_194_304,
         progress=print, record: bool = True) -> dict:
     import jax
 
-    try:
-        cache_dir = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), ".jax_cache")
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
-
     import geomesa_tpu  # noqa: F401
     from geomesa_tpu.datastore import TpuDataStore
     from geomesa_tpu.geometry.packed import packed_from_boxes
@@ -180,6 +170,8 @@ def run(n: int = 200_000_000, slice_rows: int = 4_194_304,
 
 
 if __name__ == "__main__":
+    from geomesa_tpu.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n = int(os.environ.get("POLY_SCALE_N", 200_000_000))
     out = run(n)
     print(json.dumps({"metric": "poly_scale_proof", **out}))

@@ -33,6 +33,30 @@ def test_searchsorted2_matches_bisect(rng):
         np.testing.assert_array_equal(got, ref_searchsorted2(hi, lo, qh, ql, side))
 
 
+@pytest.mark.parametrize("k1_dtype", [np.int32, np.int64])
+def test_sort_lex2_matches_lexsort(rng, k1_dtype):
+    """Two single-key sorts give the (k1, k2) order of a two-key sort,
+    negative and sentinel keys included, with payload rows intact."""
+    from geomesa_tpu.ops.search import sort_lex2
+    n = 4096
+    top = np.iinfo(k1_dtype).max
+    k1 = rng.integers(-3, 5, n).astype(k1_dtype)
+    k2 = rng.integers(-(1 << 40), 1 << 40, n)
+    k1[::97], k2[::97] = top, np.iinfo(np.int64).max   # sentinels
+    k2[1::5] = k2[0]                                    # ties in k2
+    row = np.arange(n, dtype=np.int64)
+    a, b, r, f = sort_lex2(jnp.asarray(k1), jnp.asarray(k2),
+                           jnp.asarray(row), jnp.asarray(row * 0.5))
+    order = np.lexsort((k2, k1))
+    np.testing.assert_array_equal(np.asarray(a), k1[order])
+    np.testing.assert_array_equal(np.asarray(b), k2[order])
+    r = np.asarray(r)
+    np.testing.assert_array_equal(k1[r], k1[order])
+    np.testing.assert_array_equal(k2[r], k2[order])
+    np.testing.assert_array_equal(np.sort(r), row)
+    np.testing.assert_array_equal(np.asarray(f), r * 0.5)
+
+
 def test_searchsorted2_empty_and_single():
     hi = jnp.asarray(np.array([5], dtype=np.int64))
     lo = jnp.asarray(np.array([7], dtype=np.int64))
