@@ -52,13 +52,13 @@ import numpy as np
 
 from ..metrics import (RESILIENCE_DEGRADED, RESILIENCE_RETRIES,
                        WRITE_SEALS, WRITE_SPILLS)
-from ..obs import device_span, obs_count, span as obs_span
+from ..obs import device_span, obs_count, scan_work, span as obs_span
 from ..obs.heat import (
     heat_enabled, merge_index_generations, record_index_scan,
 )
 from ..ops.search import (
     coded_pos_bits, expand_ranges, gather_capacity, pad_pow2,
-    searchsorted2, wire_dtype,
+    scan_read_bytes, searchsorted2, wire_dtype,
 )
 
 __all__ = ["LeanAttrIndex", "encode_attr_values", "encode_attr_value"]
@@ -69,6 +69,10 @@ _I64_MAX = np.int64(np.iinfo(np.int64).max)
 
 #: per-slot bytes: key int64 + sec int64 + gid int32
 SLOT_BYTES = 8 + 8 + 4
+#: what one binary-search probe reads (key + sec) and what the gather
+#: reads per candidate (gid): the ``lean.scan.bytes`` lower bound
+_SEEK_KEY_BYTES = 8 + 8
+_GID_BYTES = 4
 
 #: generation-count compile bucket for the multi-generation programs
 #: (the z3_lean._GEN_BUCKET discipline)
@@ -733,13 +737,15 @@ class LeanAttrIndex:
                 cols += [c[0], c[1]]
             self.dispatch_count += 1
             with device_span("query.scan.device", stage="sketch",
-                             runs=len(dev_scan)):
+                             runs=len(dev_scan)) as d:
+                res = _attr_sketch_multi(
+                    jnp.int64(fold.slo), jnp.int64(fold.shi),
+                    jnp.float64(fold.hlo), jnp.float64(fold.hhi),
+                    *cols, bins=int(fold.bins), depth=int(fold.depth),
+                    width=int(fold.width), is_float=is_float)
+                d.dispatched()
                 cnt, kmin, kmax, vsum, vsumsq, hist, cms = [
-                    np.asarray(a) for a in _attr_sketch_multi(
-                        jnp.int64(fold.slo), jnp.int64(fold.shi),
-                        jnp.float64(fold.hlo), jnp.float64(fold.hhi),
-                        *cols, bins=int(fold.bins), depth=int(fold.depth),
-                        width=int(fold.width), is_float=is_float)]
+                    np.asarray(a) for a in res]
             for i, g in enumerate(dev_scan):
                 n = int(cnt[i])
                 new_parts[id(g)] = RunSketch(
@@ -811,9 +817,11 @@ class LeanAttrIndex:
             self.dispatch_count += 1
             with device_span("query.scan.device", stage="probe",
                              runs=len(dev_gens),
-                             rows=int(sum(g.n for g in dev_gens))):
-                totals = np.asarray(_attr_count_multi(
-                    jklo, jkhi, jslo, jshi, *count_cols))
+                             rows=int(sum(g.n for g in dev_gens))) as d:
+                res = _attr_count_multi(jklo, jkhi, jslo, jshi,
+                                        *count_cols)
+                d.dispatched()
+                totals = np.asarray(res)
             # adaptive-replan probe point (ISSUE 19): device totals are
             # known BEFORE any gather, so aborting here discards nothing
             from ..planning.adaptive import check_replan
@@ -832,6 +840,7 @@ class LeanAttrIndex:
                                             minimum=self.DEFAULT_CAPACITY)
                             for t in totals if int(t)]
                 from ..resilience import check_cancel, fault_point
+                cand_of = {id(g): int(t) for g, t in zip(dev_gens, totals)}
                 for group, cap in zip(groups, caps):
                     # deadline yield point between group dispatches
                     # (partial mode: unscanned groups' rows are simply
@@ -849,11 +858,12 @@ class LeanAttrIndex:
                         self.dispatch_count += 1
                         with device_span("query.scan.device",
                                          stage="gather",
-                                         runs=len(group)):
+                                         runs=len(group)) as d:
                             packed = _attr_scan_coded(
                                 jklo, jkhi, jslo, jshi,
                                 jnp.asarray(qqid),
                                 *cols, capacity=cap, pos_bits=pos_bits)
+                            d.dispatched()
                             # the blocking device->host read belongs to
                             # the dispatch; host-side filtering does not
                             flat = np.asarray(packed).ravel()
@@ -866,7 +876,19 @@ class LeanAttrIndex:
                         if len(coded):
                             parts.append(coded)
                         continue
-                    parts.append(flat[flat >= 0].astype(np.int64))
+                    kept = flat[flat >= 0].astype(np.int64)
+                    parts.append(kept)
+                    # the key ranges are exact for the encoded key: the
+                    # rows returned are the scan's hits (the planner's
+                    # residual filter runs outside the index)
+                    cand = sum(cand_of[id(g)] for g in group
+                               if g is not None)
+                    scan_work(d, cand, len(group) * cap, len(kept),
+                              scan_read_bytes(
+                                  cand, _GID_BYTES, n_pad,
+                                  [self.generation_slots if g is None
+                                   else int(g.keys.shape[0])
+                                   for g in group], _SEEK_KEY_BYTES))
         host_cand_n = 0
         if host_gens:
             with obs_span("query.scan.host", runs=len(host_gens)):

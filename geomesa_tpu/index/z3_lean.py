@@ -75,18 +75,18 @@ from ..index.z3 import Z3_INDEX_VERSION, plan_z3_query, z3_sfc_for_version
 from ..metrics import (
     LEAN_COMPACTION_MERGES, LEAN_COMPACTION_ROWS,
     LEAN_DENSITY_CACHE_HITS, LEAN_DENSITY_CACHE_MISSES,
-    LEAN_SKETCH_CACHE_HITS, LEAN_SKETCH_CACHE_MISSES,
+    LEAN_SCAN_HITS, LEAN_SKETCH_CACHE_HITS, LEAN_SKETCH_CACHE_MISSES,
     PYRAMID_BUILDS, PYRAMID_BUILD_MS, PYRAMID_SERVE_HITS,
     RESILIENCE_DEGRADED, RESILIENCE_RETRIES,
     WRITE_SEALS, WRITE_SPILLS, registry as _metrics,
 )
-from ..obs import device_span, obs_count, span as obs_span
+from ..obs import device_span, obs_count, scan_work, span as obs_span
 from ..obs.heat import (
     heat_enabled, merge_index_generations, record_index_scan,
 )
 from ..ops.search import (
     coded_pos_bits, expand_ranges, gather_capacity, pad_boxes, pad_pow2,
-    pad_ranges, searchsorted2, wire_dtype,
+    pad_ranges, scan_read_bytes, searchsorted2, wire_dtype,
 )
 
 __all__ = ["LeanZ3Index", "HostStack", "merge_host_runs"]
@@ -102,6 +102,11 @@ _SENTINEL_Z = np.int64(np.iinfo(np.int64).max)
 KEYS_BYTES = 4 + 8 + 4
 PAYLOAD_BYTES = 8 + 8 + 8
 FULL_BYTES = KEYS_BYTES + PAYLOAD_BYTES
+#: what one binary-search probe reads (bins + z) and what a scan
+#: gathers per candidate before the payload (pos): the scan's
+#: ``lean.scan.bytes`` lower bound (ops/search.scan_read_bytes)
+_SEEK_KEY_BYTES = 4 + 8
+_POS_BYTES = 4
 
 
 def _append_keys_body(sfc, bins, z, pos, r, base, xs, ys, offs, bs, m):
@@ -1458,9 +1463,10 @@ class LeanZ3Index:
             n_dev = int(sum(g.n for g in dev_gens))
             with device_span("query.scan.device", stage="probe",
                              runs=len(dev_gens), rows=n_dev,
-                             bytes=n_dev * KEYS_BYTES):
-                totals = np.asarray(_lean_count_multi(rb, rlo, rhi,
-                                                      *count_cols))
+                             bytes=n_dev * KEYS_BYTES) as d:
+                res = _lean_count_multi(rb, rlo, rhi, *count_cols)
+                d.dispatched()
+                totals = np.asarray(res)
         # adaptive-replan probe point (ISSUE 19): the device totals are
         # known BEFORE any gather, so aborting here discards nothing
         from ..planning.adaptive import check_replan
@@ -1481,13 +1487,18 @@ class LeanZ3Index:
                                 jnp.asarray(bqid_c),
                                 jnp.asarray(qtlo), jnp.asarray(qthi)),
                     ra=ra, degraded_out=keys_cand)
-        # keys tier: candidate gather — host exact mask below
+        # keys tier: candidate gather — host exact mask below; its
+        # device candidates are keys_cand's rows [keys_lo, keys_hi)
+        keys_lo = keys_hi = 0
         if keys_gens and not check_cancel("query.scan.keys"):
             t_keys = totals[len(full_gens):len(dev_gens)]
             if int(t_keys.sum()):
-                keys_cand += self._scan_tier(
+                keys_dev = self._scan_tier(
                     keys_gens, t_keys, rb, rlo, rhi, rq, pos_bits,
                     exact_args=None, ra=ra, degraded_out=keys_cand)
+                keys_lo = sum(len(c) for c in keys_cand)
+                keys_hi = keys_lo + sum(len(c) for c in keys_dev)
+                keys_cand += keys_dev
         # host tier: stacked numpy seeks — flat in run count, and no
         # dispatch at all (round-4 VERDICT #9)
         host_cand_n = 0
@@ -1536,7 +1547,7 @@ class LeanZ3Index:
             # of keys/host-tier candidates) — its own scan.host stage
             # so the trace separates spill seeks from verification
             with obs_span("query.scan.host", stage="recheck",
-                          candidates=int(len(cand_hits))):
+                          candidates=int(len(cand_hits))) as rsp:
                 x, y, t = self._payload_flat()
                 qids = (cand_hits >> pos_bits).astype(np.int64)
                 cand = (cand_hits & mask_bits).astype(np.int64)
@@ -1553,6 +1564,12 @@ class LeanZ3Index:
                     keep[sel] = (in_box & (ct[sel] >= qtlo[q])
                                  & (ct[sel] <= qthi[q]))
                 cand_hits = cand_hits[keep]
+                if keys_hi > keys_lo:
+                    # the keys tier's scan hits: its device candidates
+                    # that pass this recheck (lean.scan.hits)
+                    n_hits = int(keep[keys_lo:keys_hi].sum())
+                    _metrics.counter(LEAN_SCAN_HITS).inc(n_hits)
+                    rsp.set_attr(LEAN_SCAN_HITS, n_hits)
         merged = np.concatenate([exact_hits, cand_hits])
         qids = (merged >> pos_bits).astype(np.int64)
         positions = (merged & mask_bits).astype(np.int64)
@@ -1608,9 +1625,10 @@ class LeanZ3Index:
             idx[:m] = (sorted_pos[lo:hi] - gen.base).astype(np.int32)
             self.dispatch_count += 1
             with device_span("query.materialize", stage="gather",
-                             runs=1, rows=m, bytes=m * PAYLOAD_BYTES):
+                             runs=1, rows=m, bytes=m * PAYLOAD_BYTES) as d:
                 gx, gy, gt = _lean_gather_payload(jnp.asarray(idx),
                                                   gen.x, gen.y, gen.t)
+                d.dispatched()
                 x[lo:hi] = np.asarray(gx)[:m]
                 y[lo:hi] = np.asarray(gy)[:m]
                 t[lo:hi] = np.asarray(gt)[:m]
@@ -1747,9 +1765,10 @@ class LeanZ3Index:
                 count_cols += [cols[0], cols[1]]
             self.dispatch_count += 1
             with device_span("query.scan.device", stage="probe",
-                             runs=len(dev_gens)):
-                totals = np.asarray(_lean_count_multi(rb, rlo, rhi,
-                                                      *count_cols))
+                             runs=len(dev_gens)) as d:
+                res = _lean_count_multi(rb, rlo, rhi, *count_cols)
+                d.dispatched()
+                totals = np.asarray(res)
 
         def _tier_groups(gens, tier_totals):
             cap = gather_capacity(int(tier_totals.max()),
@@ -1774,11 +1793,13 @@ class LeanZ3Index:
                                   gen.y, gen.t, jnp.int32(gen.base)))
                 self.dispatch_count += 1
                 with device_span("query.scan.device", tier="full",
-                                 runs=len(group)):
-                    grid += np.asarray(_lean_density_full(
+                                 runs=len(group)) as d:
+                    res = _lean_density_full(
                         self.sfc, rb, rlo, rhi, boxes_j, jnp.int64(lo),
                         jnp.int64(hi), env_j, *cols, capacity=cap,
-                        width=width, height=height), np.float64)
+                        width=width, height=height)
+                    d.dispatched()
+                    grid += np.asarray(res, np.float64)
         if keys_scan:
             t_keys = totals[len(full_gens):len(dev_gens)]
             # zero-candidate generations contribute a zero grid — still
@@ -1795,11 +1816,13 @@ class LeanZ3Index:
                         cols += [base[0], base[1]]
                     self.dispatch_count += 1
                     with device_span("query.scan.device", tier="keys",
-                                     runs=len(group)):
-                        stacked = np.asarray(_lean_density_keys(
+                                     runs=len(group)) as d:
+                        res = _lean_density_keys(
                             self.sfc, rb, rlo, rhi, jnp.asarray(ixy),
                             jnp.asarray(tb), env_j, *cols, capacity=cap,
-                            width=width, height=height), np.float64)
+                            width=width, height=height)
+                        d.dispatched()
+                        stacked = np.asarray(res, np.float64)
                     for i, gen in enumerate(group):
                         if gen is not None:
                             parts[id(gen)] = stacked[i]
@@ -1900,10 +1923,12 @@ class LeanZ3Index:
                    else g.z) for g in group]
             self.dispatch_count += 1
             with device_span("query.scan.device", stage="sweep",
-                             runs=len(chunk)):
-                stacked = np.asarray(_lean_density_sweep(
+                             runs=len(chunk)) as d:
+                res = _lean_density_sweep(
                     self.sfc, env_j, *zs, width=width, height=height,
-                    world=world), np.float64)
+                    world=world)
+                d.dispatched()
+                stacked = np.asarray(res, np.float64)
             for i, g in enumerate(chunk):
                 part = stacked[i]
                 grid += part
@@ -1988,10 +2013,11 @@ class LeanZ3Index:
                            else gg.z) for gg in group]
                     self.dispatch_count += 1
                     with device_span("query.scan.device", stage="sweep",
-                                     runs=1):
+                                     runs=1) as d:
                         stacked = _lean_density_sweep(
                             self.sfc, env_j, *zs, width=base,
                             height=base, world=True)
+                        d.dispatched()
                         base_dev = stacked[0]
                         lv = {base: np.asarray(base_dev, np.float64)}
                         if depth:
@@ -2065,9 +2091,11 @@ class LeanZ3Index:
                 cols += [c[0], c[1]]
             self.dispatch_count += 1
             with device_span("query.scan.device", stage="z3_cells",
-                             runs=len(chunk)):
-                stacked = np.asarray(_z3_cells_multi(
-                    jnp.int64(b0), *cols, bits=int(bits), nb=nb))
+                             runs=len(chunk)) as d:
+                res = _z3_cells_multi(jnp.int64(b0), *cols, bits=int(bits),
+                                      nb=nb)
+                d.dispatched()
+                stacked = np.asarray(res)
             for i, g in enumerate(chunk):
                 # copy, not a view: a cached view would pin the WHOLE
                 # stacked bucket (padding + live rows) in host RAM and
@@ -2178,6 +2206,7 @@ class LeanZ3Index:
                     for t in totals if int(t)]
         parts = []
         row_bytes = FULL_BYTES if tier == "full" else KEYS_BYTES
+        cand_of = {id(g): int(t) for g, t in zip(gens, totals)}
         for group, cap in zip(groups, caps):
             # deadline yield point between group dispatches: partial
             # mode stops STARTING groups (scanned ones stay exact)
@@ -2189,7 +2218,7 @@ class LeanZ3Index:
                 with device_span("query.scan.device", tier=tier,
                                  runs=sum(1 for g in group
                                           if g is not None),
-                                 rows=rows, bytes=rows * row_bytes):
+                                 rows=rows, bytes=rows * row_bytes) as d:
                     cols: list = []
                     for gen in group:
                         if gen is None:
@@ -2208,6 +2237,7 @@ class LeanZ3Index:
                         packed, nhits = _lean_scan_exact_keep(
                             rb, rlo, rhi, rq, *exact_args, *cols,
                             capacity=cap, pos_bits=pos_bits)
+                        d.dispatched()
                         k = gather_capacity(max(int(nhits), 1), minimum=8)
                         self.dispatch_count += 1
                         flat = np.asarray(_compact_coded(packed, k=k))
@@ -2220,6 +2250,7 @@ class LeanZ3Index:
                             packed = _lean_scan_coded(
                                 rb, rlo, rhi, rq, *cols,
                                 capacity=cap, pos_bits=pos_bits)
+                        d.dispatched()
                         flat = np.asarray(packed).ravel()
             except Exception as e:  # noqa: BLE001 — classified below
                 coded = self._dispatch_failed(group, e, ra, pos_bits,
@@ -2234,7 +2265,20 @@ class LeanZ3Index:
                     breaker.record_success((id(self), g.gen_id))
             # host-side candidate filtering is NOT device time — it
             # runs after the span so device_ms stays honest
-            parts.append(flat[flat >= 0].astype(np.int64))
+            kept = flat[flat >= 0].astype(np.int64)
+            parts.append(kept)
+            # the keys tier's hits are counted after the host recheck
+            cand = sum(cand_of[id(g)] for g in group if g is not None)
+            scan_work(
+                d, cand, len(group) * cap,
+                len(kept) if tier == "full" else None,
+                scan_read_bytes(
+                    cand, _POS_BYTES + (PAYLOAD_BYTES if tier == "full"
+                                        else 0),
+                    int(rb.shape[0]),
+                    [self.generation_slots if g is None else g.capacity
+                     for g in group],
+                    _SEEK_KEY_BYTES))
         return parts
 
     def _dispatch_failed(self, group, exc, ra, pos_bits, tier,

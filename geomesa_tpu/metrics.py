@@ -35,8 +35,13 @@ __all__ = ["MetricRegistry", "Timer", "Counter", "Gauge", "HistogramMetric",
            "LEAN_SKETCH_CACHE_HITS", "LEAN_SKETCH_CACHE_MISSES",
            "LEAN_SKETCH_SCANS", "LEAN_STATS_MATERIALIZED",
            "LEAN_DEVICE_DISPATCHES", "LEAN_DEVICE_MS",
+           "LEAN_DEVICE_ENQUEUE_MS", "LEAN_DEVICE_WAIT_MS",
+           "LEAN_DEVICE_INFLIGHT_SUM",
+           "LEAN_SCAN_CANDIDATES", "LEAN_SCAN_SLOTS", "LEAN_SCAN_HITS",
+           "LEAN_SCAN_BYTES",
            "JAX_COMPILE_COUNT", "JAX_COMPILE_MS", "JAX_COMPILE_FALLBACK",
            "PLAN_ESTIMATE_RATIO", "PLAN_REPLANNED",
+           "PLAN_SKETCH_BUILDS", "PLAN_SKETCH_BUILD_MS",
            "WRITE_SEALS", "WRITE_SPILLS",
            "ARROW_CHUNKS", "ARROW_ROWS", "ARROW_BYTES",
            "QUERY_TIMEOUTS", "QUERY_SHED",
@@ -74,10 +79,28 @@ LEAN_STATS_MATERIALIZED = "lean.sketch.materialized_fallbacks"
 #: device-dispatch attribution (obs.device_span): every lean device
 #: dispatch counts once (the full tier's pipelined two-phase
 #: survivors-transfer pair counts as ONE — it blocks as a unit) and
-#: its block-until-ready wall time feeds the timer — the "where does
-#: device time go" rollup (ISSUE 5)
+#: its wall time from enqueue until the result is host-addressable
+#: feeds the timer (queueing behind other threads included, so not
+#: device time)
 LEAN_DEVICE_DISPATCHES = "lean.device.dispatches"
 LEAN_DEVICE_MS = "lean.device.ms"
+#: the dispatch split: host enqueue (span entry until the jitted call
+#: returned, ``dispatched()``) and the wait after it (queueing behind
+#: other threads' programs plus this program's own device time); the
+#: backlog sum adds, per dispatch, the dispatches already inside a
+#: device span when it entered (÷ ``lean.device.dispatches`` = mean
+#: backlog a dispatch met)
+LEAN_DEVICE_ENQUEUE_MS = "lean.device.enqueue.ms"
+LEAN_DEVICE_WAIT_MS = "lean.device.wait.ms"
+LEAN_DEVICE_INFLIGHT_SUM = "lean.device.inflight.sum"
+#: scan work per lean scan dispatch (z3 ``_scan_tier``, attr gather):
+#: rows inside the covering ranges, slots the program gathers and
+#: tests (generations × capacity, padding included), rows that survive
+#: the exact mask or host recheck, and a lower bound of HBM bytes read
+LEAN_SCAN_CANDIDATES = "lean.scan.candidates"
+LEAN_SCAN_SLOTS = "lean.scan.slots"
+LEAN_SCAN_HITS = "lean.scan.hits"
+LEAN_SCAN_BYTES = "lean.scan.bytes"
 #: XLA (re)compile tracking (obs/recompile.py): backend compiles seen
 #: by the jax.monitoring listener, their durations, and the wrapped-jit
 #: fallback counter for environments without the listener API
@@ -94,6 +117,10 @@ PLAN_ESTIMATE_RATIO = "plan.estimate.ratio"
 #: and re-entered the decider with observed actuals — bounded to one
 #: per query, so this counts mispredicts bad enough to act on
 PLAN_REPLANNED = "plan.replanned"
+#: the estimator's sketch (re)builds (planning/estimator.py): first use
+#: and every change of an index's generation set pay one
+PLAN_SKETCH_BUILDS = "plan.sketch.builds"
+PLAN_SKETCH_BUILD_MS = "plan.sketch.build.ms"
 #: write-path lifecycle events (ISSUE 12): generations sealed by a
 #: rollover and key runs spilled device → host under budget pressure —
 #: counted once per event and mirrored onto the active write span via

@@ -6,8 +6,8 @@ host-spill scan / cache-miss time instead of one opaque number:
 
 * :mod:`.trace` — Dapper-style spans with contextvar propagation,
   always/ratio/slow samplers, ring + JSONL exporters, a slow-query log,
-  and the :func:`device_span` helper that attributes block-until-ready
-  device time to the owning query;
+  and the :func:`device_span` helper that attributes each dispatch's
+  enqueue and block-until-ready wait to the owning query;
 * :mod:`.recompile` — the XLA recompile tracker (jax.monitoring
   listener + wrapped-jit fallback) that turns silent retraces into
   ``jax.compile.*`` metrics and span attributes;
@@ -52,14 +52,15 @@ from .slo import ExemplarHistogram, Objective, SloPlane, slo_plane
 from .trace import (
     AlwaysSampler, JsonlExporter, NeverSampler, RatioSampler,
     RingExporter, Sampler, SlowOnlySampler, Span, Trace, Tracer,
-    current_span, current_trace_id, device_span, obs_count, span, tracer,
+    current_span, current_trace_id, device_inflight, device_span, obs_count,
+    scan_work, span, tracer,
 )
 
 __all__ = ["Span", "Trace", "Tracer", "Sampler", "AlwaysSampler",
            "NeverSampler", "RatioSampler", "SlowOnlySampler",
            "RingExporter", "JsonlExporter", "tracer", "span",
-           "device_span", "current_span", "current_trace_id", "obs_count",
-           "prometheus_text", "compile_count", "counting_jit",
+           "device_span", "device_inflight", "scan_work", "current_span",
+           "current_trace_id", "obs_count", "prometheus_text", "compile_count", "counting_jit",
            "install_recompile_tracker",
            "storage_report", "publish_storage_gauges",
            "ExplainAnalyzeResult", "explain_analyze",

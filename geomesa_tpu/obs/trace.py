@@ -39,17 +39,21 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from ..config import ObsProperties
 from ..metrics import (
-    LEAN_DEVICE_DISPATCHES, LEAN_DEVICE_MS, OBS_SPANS_DROPPED,
-    registry as _metrics,
+    LEAN_DEVICE_DISPATCHES, LEAN_DEVICE_ENQUEUE_MS, LEAN_DEVICE_INFLIGHT_SUM,
+    LEAN_DEVICE_MS, LEAN_DEVICE_WAIT_MS, LEAN_SCAN_BYTES,
+    LEAN_SCAN_CANDIDATES, LEAN_SCAN_HITS, LEAN_SCAN_SLOTS,
+    OBS_SPANS_DROPPED, registry as _metrics,
 )
 
 __all__ = ["Span", "Trace", "Tracer", "Sampler", "AlwaysSampler",
            "NeverSampler", "RatioSampler", "SlowOnlySampler",
            "RingExporter", "JsonlExporter", "tracer", "span",
-           "device_span", "current_span", "current_trace_id",
-           "obs_count"]
+           "device_span", "device_inflight", "DeviceDispatch",
+           "scan_work", "current_span", "current_trace_id", "obs_count"]
 
 
 #: process-local id source: ``uuid4`` reads ``os.urandom`` (~80 µs per
@@ -471,7 +475,11 @@ class Tracer:
                       dict(attributes))
         token = _current.set(_Ctx(trace, sp, sampler))
         try:
-            yield sp
+            # one TraceMe per recording span puts the program's spans on
+            # the profiler's host timeline beside the device ops (free
+            # when no profiler session is active)
+            with TraceAnnotation(name):
+                yield sp
         finally:
             exc = sys.exc_info()[1]
             if exc is not None:
@@ -568,31 +576,107 @@ def current_trace_id() -> str:
 #: dispatch pays the metric's own lock, not a registry lookup too
 _DEV_DISPATCHES = _metrics.counter(LEAN_DEVICE_DISPATCHES)
 _DEV_MS = _metrics.timer(LEAN_DEVICE_MS)
+_DEV_ENQUEUE_MS = _metrics.timer(LEAN_DEVICE_ENQUEUE_MS)
+_DEV_WAIT_MS = _metrics.timer(LEAN_DEVICE_WAIT_MS)
+_DEV_INFLIGHT_SUM = _metrics.counter(LEAN_DEVICE_INFLIGHT_SUM)
+
+#: guarded-by: _inflight_lock — threads inside a ``device_span`` now
+_inflight = 0
+_inflight_lock = threading.Lock()
+
+
+def device_inflight() -> int:
+    """Dispatches inside a :func:`device_span` at this moment, process-
+    wide: the device backlog a new dispatch would queue behind."""
+    with _inflight_lock:
+        return _inflight
+
+
+class DeviceDispatch:
+    """What :func:`device_span` yields: the span's attribute surface
+    plus the ``dispatched()`` mark, which works whether or not the
+    span records (the ``lean.device.*`` timers run either way)."""
+
+    __slots__ = ("span", "marked")
+
+    def __init__(self, sp):
+        self.span = sp
+        self.marked: float | None = None
+
+    def dispatched(self) -> None:
+        """Mark the end of host enqueue: call right after the jitted
+        call returns, before the blocking read of its result."""
+        if self.marked is None:
+            self.marked = time.perf_counter()
+
+    def set_attr(self, key: str, value) -> None:
+        self.span.set_attr(key, value)
 
 
 @contextlib.contextmanager
 def device_span(name: str, **attributes):
-    """A span around one device dispatch.  The block is expected to
-    block until the dispatch's results are host-addressable (the call
-    sites all materialize with ``np.asarray``/``block_until_ready``),
-    so the measured wall time IS the device round-trip; it records as
-    the span's ``device_ms``, accumulates onto the trace ROOT (whole-
-    query device attribution), and feeds the ``lean.device.*``
-    metrics whether or not a trace is active."""
+    """A span around one device dispatch, from host enqueue until its
+    results are host-addressable (the call sites all materialize with
+    ``np.asarray``/``block_until_ready`` inside the block).
+
+    ``device_ms`` is that whole wall time: host enqueue, the wait
+    behind other threads' programs already queued on the device, and
+    this program's own device time — not device time alone.  A site
+    that calls ``dispatched()`` splits it into ``enqueue_ms`` (entry to
+    the mark) and ``wait_ms`` (mark to exit), which sum to
+    ``device_ms``; an unmarked site records ``device_ms`` only.
+    ``inflight`` is the number of dispatches already inside a device
+    span when this one entered.  ``device_ms`` accumulates onto the
+    trace ROOT, and the ``lean.device.*`` metrics are fed whether or
+    not a trace is active."""
+    global _inflight
+    with _inflight_lock:
+        inflight = _inflight
+        _inflight += 1
+    _DEV_INFLIGHT_SUM.inc(inflight)
     t0 = time.perf_counter()
-    with tracer.span(name, kind="device", **attributes) as sp:
-        try:
-            yield sp
-        finally:
-            ms = (time.perf_counter() - t0) * 1e3
-            _DEV_DISPATCHES.inc()
-            _DEV_MS.update(ms)
-            sp.set_attr("device_ms", round(ms, 3))
-            ctx = _current.get()
-            if ctx is not None and ctx.trace is not None \
-                    and ctx.trace.root_span is not None \
-                    and ctx.trace.root_span is not sp:
-                ctx.trace.root_span.add_attr("device_ms", round(ms, 3))
+    try:
+        with tracer.span(name, kind="device", inflight=inflight,
+                         **attributes) as sp:
+            d = DeviceDispatch(sp)
+            try:
+                yield d
+            finally:
+                t1 = time.perf_counter()
+                ms = (t1 - t0) * 1e3
+                _DEV_DISPATCHES.inc()
+                _DEV_MS.update(ms)
+                sp.set_attr("device_ms", round(ms, 3))
+                if d.marked is not None:
+                    enqueue_ms = (d.marked - t0) * 1e3
+                    _DEV_ENQUEUE_MS.update(enqueue_ms)
+                    _DEV_WAIT_MS.update(ms - enqueue_ms)
+                    sp.set_attr("enqueue_ms", round(enqueue_ms, 3))
+                    sp.set_attr("wait_ms", round(ms - enqueue_ms, 3))
+                ctx = _current.get()
+                if ctx is not None and ctx.trace is not None \
+                        and ctx.trace.root_span is not None \
+                        and ctx.trace.root_span is not sp:
+                    ctx.trace.root_span.add_attr("device_ms", round(ms, 3))
+    finally:
+        with _inflight_lock:
+            _inflight -= 1
+
+
+def scan_work(d: DeviceDispatch, candidates: int, slots: int,
+              hits: int | None, read_bytes: int) -> None:
+    """Count one lean scan dispatch's work onto the ``lean.scan.*``
+    counters and its span: ``candidates`` (rows inside the covering
+    ranges), ``slots`` (generations x capacity the program gathers and
+    tests, padding included), ``hits`` (rows left after the exact mask;
+    None where they are counted later, after a host recheck) and
+    ``read_bytes`` (``ops.search.scan_read_bytes``)."""
+    for key, n in ((LEAN_SCAN_CANDIDATES, candidates),
+                   (LEAN_SCAN_SLOTS, slots), (LEAN_SCAN_HITS, hits),
+                   (LEAN_SCAN_BYTES, read_bytes)):
+        if n is not None:
+            _metrics.counter(key).inc(int(n))
+            d.set_attr(key, int(n))
 
 
 def obs_count(metric_name: str, n: int = 1) -> None:

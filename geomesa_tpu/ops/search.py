@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["searchsorted2", "expand_ranges", "gather_capacity",
+           "scan_read_bytes",
            "sort_lex2", "coded_pos_bits", "wire_dtype", "pack_wire", "pack_coded",
            "run_packed_query"]
 
@@ -151,6 +152,23 @@ def gather_capacity(total: int, minimum: int = 1024) -> int:
     while cap < total:
         cap *= 2
     return cap
+
+
+def scan_read_bytes(candidates: int, gather_bytes: int, n_ranges: int,
+                    gen_slots, key_bytes: int) -> int:
+    """A lower bound of the HBM bytes one batched range scan reads,
+    from shapes: every candidate's gathered columns, plus the seeks —
+    per generation of ``gen_slots`` sorted slots, two binary searches
+    (the range's lower and upper bound) for each of the ``n_ranges``
+    ranges the program carries, each probe reading one key::
+
+        candidates x gather_bytes
+          + sum over generations of n_ranges x 2 x ceil(log2 slots) x key_bytes
+
+    Expansion, masks and writes are left out, so the true traffic is
+    higher."""
+    seeks = sum(max(1, int(s) - 1).bit_length() for s in gen_slots)
+    return int(candidates * gather_bytes + n_ranges * 2 * seeks * key_bytes)
 
 
 def sort_lex2(k1, k2, *cols):

@@ -38,7 +38,9 @@ stats tier, then to the named heuristic constants
 
 from __future__ import annotations
 
+import contextlib
 import math
+import time
 
 import numpy as np
 
@@ -67,6 +69,23 @@ _MAX_RANGES_FLOOR, _MAX_RANGES_CEIL = 512, 1 << 14
 
 _NUMERIC_HIST_TYPES = frozenset(
     {"int", "integer", "long", "float", "double"})
+
+
+@contextlib.contextmanager
+def _sketch_build(index: str):
+    """One (re)build of an estimation table: a ``plan.sketch.build``
+    span (mapped to no attribution stage) and the ``plan.sketch.*``
+    counter and timer, which the first queries after start-up or after
+    a write pay."""
+    from ..metrics import (PLAN_SKETCH_BUILD_MS, PLAN_SKETCH_BUILDS,
+                           registry)
+    from ..obs import span as obs_span
+    t0 = time.perf_counter()
+    with obs_span("plan.sketch.build", index=index):
+        yield
+    registry.counter(PLAN_SKETCH_BUILDS).inc()
+    registry.timer(PLAN_SKETCH_BUILD_MS).update(
+        (time.perf_counter() - t0) * 1e3)
 
 
 def _gen_signature(idx) -> tuple | None:
@@ -121,16 +140,17 @@ class CardinalityEstimator:
         cached = self._z3_cached
         if cached is not None and cached[0] == sig:
             return cached
-        bits = self._cell_bits(idx)
-        cells = idx.z3_cell_counts(bits)
-        cpb = 1 << bits
-        flat = np.fromiter((b * cpb + c for b, c in cells),
-                           np.int64, len(cells))
-        cnt = np.fromiter(cells.values(), np.int64, len(cells))
-        order = np.argsort(flat)
-        keys = flat[order]
-        cum = np.concatenate([np.zeros(1, np.int64),
-                              np.cumsum(cnt[order])])
+        with _sketch_build("z3"):
+            bits = self._cell_bits(idx)
+            cells = idx.z3_cell_counts(bits)
+            cpb = 1 << bits
+            flat = np.fromiter((b * cpb + c for b, c in cells),
+                               np.int64, len(cells))
+            cnt = np.fromiter(cells.values(), np.int64, len(cells))
+            order = np.argsort(flat)
+            keys = flat[order]
+            cum = np.concatenate([np.zeros(1, np.int64),
+                                  np.cumsum(cnt[order])])
         cached = (sig, keys, cum, idx, bits)
         self._z3_cached = cached
         return cached
@@ -185,8 +205,9 @@ class CardinalityEstimator:
         cached = self._attr_cached.get(attr)
         if cached is not None and cached[0] == sig:
             return cached
-        fold = self._attr_fold(attr, idx)
-        sketch = idx.sketch_scan(fold)
+        with _sketch_build(f"attr:{attr}"):
+            fold = self._attr_fold(attr, idx)
+            sketch = idx.sketch_scan(fold)
         cached = (sig, sketch, fold, idx)
         self._attr_cached[attr] = cached
         return cached

@@ -277,14 +277,15 @@ class QueryPlanner:
                     take_cols.update((f"{p}_x", f"{p}_y", f"{p}_bbox"))
                 else:
                     take_cols.add(p)
-        result_batch = batch.take(local_rows, columns=take_cols)
-        if properties is not None:
-            result_batch = _project(result_batch, properties)
-        if query.crs:
-            # result-side reprojection (QueryPlanner.scala:74-81)
-            from ..geometry.crs import reproject_batch
-            result_batch = reproject_batch(result_batch, query.crs)
-            explain(lambda: f"Reprojected to {query.crs}")
+        with obs_span("query.materialize", rows=int(len(local_rows))):
+            result_batch = batch.take(local_rows, columns=take_cols)
+            if properties is not None:
+                result_batch = _project(result_batch, properties)
+            if query.crs:
+                # result-side reprojection (QueryPlanner.scala:74-81)
+                from ..geometry.crs import reproject_batch
+                result_batch = reproject_batch(result_batch, query.crs)
+                explain(lambda: f"Reprojected to {query.crs}")
         explain.pop()
         return QueryResult(result_batch, positions, strategy, plan_ms,
                            scan_ms, local_rows=local_rows)

@@ -46,6 +46,7 @@ from ..metrics import (SERVING_BATCH_WINDOWS, SERVING_COALESCE_MS,
                        SERVING_FUSED_REQUESTS, SERVING_RIDER_EXPIRED,
                        SERVING_TENANT_SHED)
 from ..metrics import registry as _registry
+from ..obs import device_inflight
 from ..resilience import (Backpressure, CancelScope, QueryTimeout,
                           deadline_scope)
 
@@ -204,8 +205,14 @@ class FusionScheduler:
                 if me.done:
                     return self._finish(me)
                 if q.leader is me:
+                    t_linger = time.perf_counter()
                     batch = self._collect(q, me, window_ms, max_batch,
                                           quantum)
+                    # the batch closes here: what the leader lingered,
+                    # and the device backlog it will queue behind
+                    closed = {"linger_ms": round(
+                        (time.perf_counter() - t_linger) * 1000.0, 3),
+                        "inflight": device_inflight()}
                     q.leader = None
                     self._cond.notify_all()
                     break
@@ -230,7 +237,7 @@ class FusionScheduler:
                     q.leader = me
         # lock dropped — run the fused dispatch on this (leader) thread
         try:
-            self._run_batch(batch, dispatch, schema)
+            self._run_batch(batch, dispatch, schema, closed)
         finally:
             with self._cond:
                 self._cond.notify_all()
@@ -316,10 +323,12 @@ class FusionScheduler:
                 del q.tenants[m.tenant]
                 q.rr.remove(m.tenant)
 
-    def _run_batch(self, batch, dispatch, schema):
+    def _run_batch(self, batch, dispatch, schema, closed):
         """Execute one fused batch (leader's thread, no scheduler
         lock).  Sets every member's positions/error/timed_out and
-        ``done``; the caller notifies waiters afterwards."""
+        ``done``; the caller notifies waiters afterwards.  ``closed``
+        (``linger_ms``, ``inflight`` when the batch closed) lands on
+        every round's ``serving.fuse`` span."""
         from ..obs import span as obs_span
         pending = [m for m in batch if not m.done]
         first_round = True
@@ -341,7 +350,7 @@ class FusionScheduler:
             try:
                 with obs_span("serving.fuse", schema=schema,
                               batch=len(pending),
-                              windows=len(windows)) as sp:
+                              windows=len(windows), **closed) as sp:
                     if margin is not None:
                         # the batch runs under its members' minimum
                         # remaining margin, in partial mode: expiry

@@ -1,12 +1,10 @@
-"""Closure timing + device trace annotations.
+"""Closure timing.
 
 The analog of the reference's MethodProfiling
 (geomesa-utils/.../stats/MethodProfiling.scala — ``profile(label)``
-closure timing feeding the explainer/logs) fused with the TPU-side
-plan from SURVEY.md §5: each profiled phase also becomes a
-``jax.profiler.TraceAnnotation`` so device traces captured with
-``jax.profiler.trace`` show query phases (planning / seek / gather /
-filter) alongside the XLA ops they launched.
+closure timing feeding the explainer/logs).  Query phases reach
+``jax.profiler`` traces through the tracer's spans (obs/trace.py),
+each of which enters a ``TraceAnnotation`` of its name.
 """
 
 from __future__ import annotations
@@ -44,22 +42,14 @@ class _Span:
 def profile(label: str, sink: Timings | None = None, explain=None):
     """Time a block; optionally record into ``sink`` and/or an Explainer.
 
-    Wraps the block in a jax TraceAnnotation when jax is importable so
-    profiler captures attribute device work to the phase.  Yields a span
-    whose ``.ms`` holds the elapsed time after exit; timings are recorded
-    even when the block raises (failing executions are exactly the ones a
-    profiler must show).
+    Yields a span whose ``.ms`` holds the elapsed time after exit;
+    timings are recorded even when the block raises (failing executions
+    are exactly the ones a profiler must show).
     """
-    try:
-        import jax.profiler
-        ann = jax.profiler.TraceAnnotation(label)
-    except Exception:  # pragma: no cover — jax always present in-image
-        ann = contextlib.nullcontext()
     span = _Span()
     t0 = time.perf_counter()
     try:
-        with ann:
-            yield span
+        yield span
     finally:
         span.ms = (time.perf_counter() - t0) * 1e3
         if sink is not None:
