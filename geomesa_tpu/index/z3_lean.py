@@ -497,11 +497,16 @@ def _make_sentinel_cols(tier: str, slots: int):
     padded program has the uniform shape ``(slots,) × G_pad`` and
     compiles once per BUCKET, not once per real generation count — at
     60 sorted runs the difference is minutes of compile per
-    checkpoint.  All-sentinel keys match zero
-    seeks, so padding still does no real expand work (round-3 VERDICT
-    weak #5); one shared buffer per index is passed for every padded
-    slot (cached per-INSTANCE so its device arrays die with the index
-    and eviction cannot steal another live index's padding)."""
+    checkpoint.  All-sentinel keys match zero seeks, but a padded
+    generation is not free: the programs still binary-search its full
+    slot count for every range, and a row scan still expands, gathers
+    and masks its whole capacity.  So while the totals probe and the
+    density scans pad to ``_GEN_BUCKET``, a row scan
+    (``LeanZ3Index._scan_tier``) with fewer live generations than a
+    bucket dispatches each alone and pads nothing.  One shared buffer
+    per index is passed for every padded slot (cached per-INSTANCE so
+    its device arrays die with the index and eviction cannot steal
+    another live index's padding)."""
     bins = jnp.full((slots,), _SENTINEL_BIN, jnp.int32)
     z = jnp.full((slots,), _SENTINEL_Z, jnp.int64)
     pos = jnp.full((slots,), -1, jnp.int32)
@@ -879,7 +884,20 @@ class LeanZ3Index:
     #: each slice exactly once — the LSM run-per-flush shape — while
     #: larger generations amortize query seeks.
     GENERATION_SLOTS = 1 << 24
+    #: candidate-buffer floor of the density scans; the row scans
+    #: (``_scan_tier``) floor at SCAN_MIN_CAPACITY
     DEFAULT_CAPACITY = 1 << 15
+    #: candidate-buffer floor of a row scan dispatch: its capacity is
+    #: the next power of two ≥ the candidates of its generation (the
+    #: largest total, in a batched dispatch), and never below this.  Every slot up to it is expanded, gathered and
+    #: masked, so a floor above the typical total is pure padding.
+    #: Chosen on a TPU v5e, 30 s windows of the benchmark's dashboard
+    #: and analyst cells: 2^12 read 60.5 and 40.3 queries/s (p95 1.64 s,
+    #: 343 ms) against 45.2 and 34.6 (2.09 s, 419 ms) at 2^15, with 17
+    #: and 7 programs loaded in the window against 5 and 1; a sweep of
+    #: a batched scan read 7.2 ms a scan program at 2^12 against 10.1
+    #: at 2^10 and 21.6 at 2^13, with 26, 36 and 25 in-window loads.
+    SCAN_MIN_CAPACITY = 1 << 12
     #: slot budget for a batched (G × capacity) candidate buffer; beyond
     #: it queries fall back to per-generation dispatches sized by each
     #: generation's own total
@@ -2134,8 +2152,8 @@ class LeanZ3Index:
     @staticmethod
     def _pad_bucket(gens: list) -> list:
         """Pad a generation list to the compile bucket with ``None``
-        (the shared empty sentinel generation — zero seek/gather work,
-        round-3 VERDICT weak #5)."""
+        (the shared empty sentinel generation: no candidates, but its
+        seeks still run — see _make_sentinel_cols)."""
         n_pad = (-len(gens)) % _GEN_BUCKET
         return list(gens) + [None] * n_pad
 
@@ -2154,15 +2172,16 @@ class LeanZ3Index:
 
     def _scan_tier(self, gens, totals, rb, rlo, rhi, rq, pos_bits,
                    exact_args, ra=None, degraded_out=None) -> list:
-        """Run one tier's batched scan, falling back to per-generation
-        dispatches (each sized by its OWN total) when the shared-
-        capacity batched buffer would exceed BATCH_SCAN_BUDGET slots.
-        Only generations with CANDIDATES scan at all: under
-        time-partitioned ingest a window's bins live in a handful of
-        generations, and carrying the other 50 at the shared capacity
-        tripled warm queries at 1B (measured; the probe already knows
-        the per-generation totals).  Returns flat coded arrays
-        (padding stripped).
+        """Run one tier's scan.  Only generations with CANDIDATES scan
+        at all: under time-partitioned ingest a window's bins live in a
+        handful of generations, and carrying the other 50 at the shared
+        capacity tripled warm queries at 1B (measured; the probe
+        already knows the per-generation totals).  A bucket or more of
+        live generations runs as one batched dispatch at the capacity
+        of the largest total; fewer, or a batched buffer over
+        BATCH_SCAN_BUDGET slots, run one dispatch per generation sized
+        by its OWN total (each floored at SCAN_MIN_CAPACITY).  Returns
+        flat coded arrays (padding stripped).
 
         Degraded execution (ISSUE 16): with ``ra`` (the HOST range
         dict) and ``degraded_out`` given, a transient device failure
@@ -2195,15 +2214,20 @@ class LeanZ3Index:
         gens = [g for g, _ in live]
         totals = np.asarray([t for _, t in live])
         capacity = gather_capacity(int(totals.max()),
-                                   minimum=self.DEFAULT_CAPACITY)
+                                   minimum=self.SCAN_MIN_CAPACITY)
         padded = self._pad_bucket(gens)
-        if len(padded) * capacity <= self.BATCH_SCAN_BUDGET:
+        # fewer live generations than a bucket dispatch one by one: a
+        # padded generation costs its seeks and a whole capacity of
+        # expand, gathers and masks, and a program per live count would
+        # multiply the shapes a warm server has to load
+        if (len(gens) >= _GEN_BUCKET
+                and len(padded) * capacity <= self.BATCH_SCAN_BUDGET):
             groups = [padded]
             caps = [capacity]
         else:
-            groups = [[g] for g, t in zip(gens, totals) if int(t)]
-            caps = [gather_capacity(int(t), minimum=self.DEFAULT_CAPACITY)
-                    for t in totals if int(t)]
+            groups = [[g] for g in gens]
+            caps = [gather_capacity(int(t), minimum=self.SCAN_MIN_CAPACITY)
+                    for t in totals]
         parts = []
         row_bytes = FULL_BYTES if tier == "full" else KEYS_BYTES
         cand_of = {id(g): int(t) for g, t in zip(gens, totals)}

@@ -107,7 +107,9 @@ def test_sentinel_padding_no_extra_dispatches(data):
         idx.append(x[s:s + 1000], y[s:s + 1000], t[s:s + 1000])
     assert len(idx.generations) == 5
     from geomesa_tpu.index.z3_lean import _GEN_BUCKET
-    assert _GEN_BUCKET == 4  # 5 gens pad to 8
+    # 5 gens pad to 8 in the totals probe, and in the scan because all
+    # 5 are live: fewer than a bucket of live gens would scan unpadded
+    assert _GEN_BUCKET == 4
     # the shared per-instance sentinel generation is full-size (uniform
     # program shapes -> one compile per bucket) and matches zero seeks
     sb, sz, sp = idx._sentinel_cols("keys")

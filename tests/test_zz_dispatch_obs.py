@@ -18,6 +18,7 @@ from geomesa_tpu.index.attr_lean import LeanAttrIndex
 from geomesa_tpu.index.z3 import plan_z3_query
 from geomesa_tpu.index.z3_lean import (
     _GEN_BUCKET, _MAX_RANGES_PER_WINDOW, LeanZ3Index, _bins_spanned,
+    _lean_scan_exact_coded,
 )
 from geomesa_tpu.metrics import (
     LEAN_DEVICE_DISPATCHES, LEAN_DEVICE_ENQUEUE_MS, LEAN_DEVICE_INFLIGHT_SUM,
@@ -25,7 +26,7 @@ from geomesa_tpu.metrics import (
     LEAN_SCAN_HITS, LEAN_SCAN_SLOTS, PLAN_SKETCH_BUILD_MS,
     PLAN_SKETCH_BUILDS, registry,
 )
-from geomesa_tpu.ops.search import gather_capacity, scan_read_bytes
+from geomesa_tpu.ops.search import gather_capacity, pad_pow2, scan_read_bytes
 
 MS = 1514764800000
 DAY = 86_400_000
@@ -136,11 +137,14 @@ def test_inflight_counts_the_dispatches_already_inside():
 # -- scan work: hand counts --------------------------------------------
 
 def _index(slots, sizes, payload_on_device=True, seed=41):
+    """Generations of ``sizes`` rows, appended in time order (as a
+    chronological feed fills them): each generation holds a stretch of
+    the 14 days."""
     rng = np.random.default_rng(seed)
     n = sum(sizes)
     x = rng.uniform(-75, -73, n)
     y = rng.uniform(40, 42, n)
-    t = rng.integers(MS, MS + 14 * DAY, n)
+    t = np.sort(rng.integers(MS, MS + 14 * DAY, n))
     idx = LeanZ3Index(period="week", generation_slots=slots,
                       payload_on_device=payload_on_device)
     s = 0
@@ -174,28 +178,71 @@ def _brute(x, y, t, box, lo, hi):
                 & (y <= box[3]) & (t >= lo) & (t <= hi)).sum())
 
 
+#: windows whose candidates lie in 1, 2 and 3 of the three generations
+#: (rows 0-8191 fall on days 0-5.9, 8192-16383 on days 5.9-11.8)
+LIVE_WINDOWS = {1: (1, 4), 2: (4, 9), 3: (3, 13)}
+
+
+@pytest.mark.parametrize("live", sorted(LIVE_WINDOWS),
+                         ids=[f"{n}of3" for n in sorted(LIVE_WINDOWS)])
 @pytest.mark.parametrize("payload_on_device", [True, False],
                          ids=["full", "keys"])
-def test_scan_work_matches_a_hand_count(payload_on_device):
-    """Three generations pad to a bucket of four and share the capacity
-    of the largest total; the keys tier counts its hits after the host
+def test_scan_work_matches_a_hand_count(payload_on_device, live):
+    """Fewer live generations (those with candidates) than a bucket
+    dispatch one by one, each at the capacity of its own total floored
+    at SCAN_MIN_CAPACITY; the keys tier counts its hits after the host
     recheck."""
     slots = 8192
     idx, x, y, t = _index(slots, [slots, slots, 3000], payload_on_device)
     assert len(idx.generations) == 3 < _GEN_BUCKET
     box = (-74.6, 40.4, -73.7, 41.3)
-    lo, hi = MS + 2 * DAY, MS + 9 * DAY
+    d0, d1 = LIVE_WINDOWS[live]
+    lo, hi = MS + d0 * DAY, MS + d1 * DAY
     idx.query([box], lo, hi)                      # compile
     per_gen, _ = _hand_candidates(idx, box, lo, hi)
+    assert sum(1 for c in per_gen if c) == live
     before = _counts(*SCAN)
     got = idx.query([box], lo, hi)
     cand, n_slots, hits, nbytes = _delta(before, SCAN)
-    cap = gather_capacity(max(per_gen), minimum=idx.DEFAULT_CAPACITY)
     assert cand == sum(per_gen)
-    assert n_slots == _GEN_BUCKET * cap
+    assert n_slots == sum(gather_capacity(c, minimum=idx.SCAN_MIN_CAPACITY)
+                          for c in per_gen if c)
     assert hits == len(got) == _brute(x, y, t, box, lo, hi)
     assert 0 < hits < cand < n_slots
     assert nbytes >= cand * 4
+
+
+def test_scan_programs_stay_log_many_over_growing_windows():
+    """Windows of growing box and span over three time-ordered
+    generations compile one scan program per shape they meet: the
+    planner's power-of-two range bucket and the capacity bucket of a
+    live generation's total.  With fewer live generations than a bucket
+    the generation count is no part of the shape, and the capacity
+    takes log-many values."""
+    slots = 1 << 14
+    idx, x, y, t = _index(slots, [slots, slots, 5000], seed=53)
+    assert len(idx.generations) == 3 < _GEN_BUCKET
+    before = _lean_scan_exact_coded._cache_size()
+    shapes = set()
+    # candidates in 1, 2, then 3 generations (rows 16384 and 32768
+    # fall on days 6.1 and 12.1)
+    for d0, d1 in ((8.5, 9.5), (4, 10), (3, 14)):
+        for w in (0.05, 0.3, 1.0):
+            box = (-74.0 - w, 41.0 - w, -74.0 + w, 41.0 + w)
+            lo, hi = MS + int(d0 * DAY), MS + int(d1 * DAY)
+            per_gen, n_ranges = _hand_candidates(idx, box, lo, hi)
+            got = idx.query([box], lo, hi)
+            assert len(got) == _brute(x, y, t, box, lo, hi)
+            shapes |= {(pad_pow2(n_ranges),
+                        gather_capacity(c, minimum=idx.SCAN_MIN_CAPACITY))
+                       for c in per_gen if c}
+    grown = _lean_scan_exact_coded._cache_size() - before
+    assert 1 <= grown <= len(shapes)
+    caps = {c for _, c in shapes}
+    cap_buckets = {gather_capacity(n, minimum=idx.SCAN_MIN_CAPACITY)
+                   for n in range(1, slots + 1)}
+    assert len(caps) >= 2 and caps <= cap_buckets
+    assert len(cap_buckets) == (slots // idx.SCAN_MIN_CAPACITY).bit_length()
 
 
 def test_scan_bytes_follow_the_documented_formula():
