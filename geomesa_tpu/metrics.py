@@ -38,7 +38,7 @@ __all__ = ["MetricRegistry", "Timer", "Counter", "Gauge", "HistogramMetric",
            "LEAN_DEVICE_ENQUEUE_MS", "LEAN_DEVICE_WAIT_MS",
            "LEAN_DEVICE_INFLIGHT_SUM",
            "LEAN_SCAN_CANDIDATES", "LEAN_SCAN_SLOTS", "LEAN_SCAN_HITS",
-           "LEAN_SCAN_BYTES",
+           "LEAN_SCAN_BYTES", "LEAN_PROBE_GENERATIONS", "LEAN_PROBE_SEEKS",
            "JAX_COMPILE_COUNT", "JAX_COMPILE_MS", "JAX_COMPILE_FALLBACK",
            "PLAN_ESTIMATE_RATIO", "PLAN_REPLANNED",
            "PLAN_SKETCH_BUILDS", "PLAN_SKETCH_BUILD_MS",
@@ -101,6 +101,12 @@ LEAN_SCAN_CANDIDATES = "lean.scan.candidates"
 LEAN_SCAN_SLOTS = "lean.scan.slots"
 LEAN_SCAN_HITS = "lean.scan.hits"
 LEAN_SCAN_BYTES = "lean.scan.bytes"
+#: the lean z3 totals probe (``LeanZ3Index.query_many``): device
+#: generations held at each probe, and the generations it sought
+#: (sentinel padding included) after skipping those whose time span
+#: misses every window of the batch
+LEAN_PROBE_GENERATIONS = "lean.probe.generations"
+LEAN_PROBE_SEEKS = "lean.probe.seeks"
 #: XLA (re)compile tracking (obs/recompile.py): backend compiles seen
 #: by the jax.monitoring listener, their durations, and the wrapped-jit
 #: fallback counter for environments without the listener API

@@ -355,8 +355,10 @@ class FusionScheduler:
                         # the batch runs under its members' minimum
                         # remaining margin, in partial mode: expiry
                         # stops the scan at a yield point — it never
-                        # raises out of a shared dispatch
-                        bscope = CancelScope(margin, True)
+                        # raises out of a shared dispatch.  A spent
+                        # margin reads 0.0, which CancelScope takes for
+                        # no deadline: floor it so it still expires
+                        bscope = CancelScope(max(margin, 1e-6), True)
                         with deadline_scope(scope=bscope):
                             hits = dispatch(windows)
                         expired_mid = bscope.timed_out

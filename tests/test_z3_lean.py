@@ -309,3 +309,135 @@ def test_payload_provider_shares_store_columns(data):
     np.testing.assert_array_equal(
         idx.query([box], None, None),
         _brute(x, y, t, [box], None, None))
+
+
+def _probe_seeks(idx, windows):
+    """(answers, generations the totals probe sought) of one batch."""
+    from geomesa_tpu.metrics import LEAN_PROBE_SEEKS, registry
+    c = registry.counter(LEAN_PROBE_SEEKS)
+    before = c.count
+    got = idx.query_many(windows)
+    return got, c.count - before
+
+
+def _ordered(data, shuffle=False, **kw):
+    """45,000 rows in time order (or shuffled) at 2^14 slots: three
+    generations of about 5, 5 and 4 days."""
+    x, y, t = data
+    n = 45_000
+    order = np.argsort(t[:n], kind="stable")
+    if shuffle:
+        order = np.random.default_rng(5).permutation(order)
+    xs, ys, ts = x[:n][order], y[:n][order], t[:n][order]
+    idx = LeanZ3Index(period="week", generation_slots=1 << 14, **kw)
+    for s in range(0, n, 10_000):
+        idx.append(xs[s:s + 10_000], ys[s:s + 10_000], ts[s:s + 10_000])
+    assert len(idx.generations) == 3
+    return idx, xs, ys, ts
+
+
+def _cancel_after_first_decompose(monkeypatch, poison: int):
+    """Stop the decompose after its first window, and fill what
+    ``np.empty`` hands out with ``poison`` (any content is allowed), so
+    a probe that read an unplanned window's time would see it."""
+    import geomesa_tpu.resilience as res
+    planned = []
+
+    def check(point="", scope=None):
+        if point != "query.decompose":
+            return False
+        planned.append(point)
+        return len(planned) > 1
+    empty = np.empty
+
+    def poisoned(shape, dtype=float, **kw):
+        try:
+            return np.full(shape, poison, dtype=dtype, **kw)
+        except (TypeError, ValueError):
+            return empty(shape, dtype=dtype, **kw)
+    monkeypatch.setattr(res, "check_cancel", check)
+    monkeypatch.setattr(np, "empty", poisoned)
+
+
+BOX = (-74.5, 40.5, -73.5, 41.5)
+
+
+@pytest.mark.parametrize("case", ["inside_one", "across_boundary",
+                                  "untimed", "shuffled", "merged",
+                                  "cancelled_decompose"])
+def test_probe_seeks_only_generations_meeting_the_windows(
+        data, case, monkeypatch):
+    """The totals probe skips every generation whose time span misses
+    all windows of the batch, pads nothing below a bucket, and answers
+    as the brute force does."""
+    idx, xs, ys, ts = _ordered(data, shuffle=case == "shuffled",
+                               payload_on_device=case != "merged")
+    g0, g1, g2 = idx.generations
+    for i, g in enumerate(idx.generations):     # each slice widens
+        sl = ts[i << 14:(i + 1) << 14]
+        assert (g.t_lo, g.t_hi) == (int(sl.min()), int(sl.max()))
+    mid0 = (g0.t_lo + g0.t_hi) // 2
+    inside = ([BOX], mid0 - DAY // 2, mid0 + DAY // 2)
+    windows, want_seeks = [inside], 1
+    if case == "across_boundary":
+        windows = [([BOX], g0.t_hi - DAY // 4, g1.t_lo + DAY // 4)]
+        want_seeks = 2
+    elif case == "untimed":
+        windows = [([BOX], None, None)]
+        want_seeks = 3
+    elif case == "shuffled":
+        want_seeks = 3
+    elif case == "merged":
+        assert idx.compact(factor=2)["merged_groups"] == 1
+        merged, live = idx.generations
+        assert (merged.tier, merged.t_lo, merged.t_hi) == (
+            "keys", g0.t_lo, g1.t_hi)
+        assert (live.t_lo, live.t_hi) == (g2.t_lo, g2.t_hi)
+        mid1 = (g1.t_lo + g1.t_hi) // 2
+        windows = [([BOX], mid1 - DAY // 2, mid1 + DAY // 2)]
+    elif case == "cancelled_decompose":
+        mid2 = (g2.t_lo + g2.t_hi) // 2
+        _cancel_after_first_decompose(monkeypatch, poison=mid2)
+        windows = [inside, ([BOX], mid2 - DAY // 2, mid2 + DAY // 2)]
+    got, seeks = _probe_seeks(idx, windows)
+    assert seeks == want_seeks
+    for q, (g, (bxs, lo, hi)) in enumerate(zip(got, windows)):
+        want = _brute(xs, ys, ts, bxs, lo, hi)
+        if case == "cancelled_decompose" and q > 0:
+            want = want[:0]          # never planned: scans nothing
+        assert len(want) or case == "cancelled_decompose"
+        np.testing.assert_array_equal(g, want)
+
+
+@pytest.mark.parametrize("n_windows,width", [(1, 8), (3, 8), (8, 8),
+                                             (9, 16)])
+def test_exact_scan_pads_windows_to_the_bucket(data, monkeypatch,
+                                               n_windows, width):
+    """The exact scan's per-window arrays (boxes, their qids, the time
+    bounds) pad to ``_WINDOW_BUCKET`` or the next power of two past it,
+    so batches of one to eight windows share one program; answers stay
+    the brute force's."""
+    import geomesa_tpu.index.z3_lean as zl
+    x, y, t = data
+    n = 10_000
+    idx = LeanZ3Index(period="week", generation_slots=1 << 14)
+    idx.append(x[:n], y[:n], t[:n])
+    seen = []
+    scan = zl._lean_scan_exact_coded
+
+    def recording(rb, rlo, rhi, rqid, boxes, bqid, qtlo, qthi, *cols,
+                  **kw):
+        seen.append((boxes.shape[0], bqid.shape[0], qtlo.shape[0],
+                     qthi.shape[0]))
+        return scan(rb, rlo, rhi, rqid, boxes, bqid, qtlo, qthi, *cols,
+                    **kw)
+    monkeypatch.setattr(zl, "_lean_scan_exact_coded", recording)
+    windows = [([(x[i] - .01, y[i] - .01, x[i] + .01, y[i] + .01)],
+                int(t[i]) - DAY, int(t[i]) + DAY)
+               for i in range(n_windows)]
+    got = idx.query_many(windows)
+    assert seen and all(s == (width,) * 4 for s in seen)
+    for g, (bxs, lo, hi) in zip(got, windows):
+        want = _brute(x[:n], y[:n], t[:n], bxs, lo, hi)
+        assert len(want)
+        np.testing.assert_array_equal(g, want)

@@ -257,6 +257,22 @@ def test_expired_rider_drops_without_poisoning_batch():
     assert admission_gate.inflight == 0
 
 
+@pytest.mark.parametrize("partial", [True, False])
+def test_expired_leader_times_out(partial):
+    """A request alone leads its own batch: with its deadline already
+    spent it times out (empty partial, or QueryTimeout), never a full
+    answer under no deadline."""
+    ds = _mk_store("sv_d3", n=500)
+    if partial:
+        r = ds.query_fused("sv_d3", BBOX, timeout_ms=1e-6,
+                           partial_results=True)
+        assert r.timed_out is True and len(r.positions) == 0
+    else:
+        with pytest.raises(QueryTimeout):
+            ds.query_fused("sv_d3", BBOX, timeout_ms=1e-6)
+    assert admission_gate.inflight == 0
+
+
 def test_fused_generous_timeout_exact():
     ds = _mk_store("sv_d2", n=500)
     r = ds.query_fused("sv_d2", BBOX, timeout_ms=60_000.0)
