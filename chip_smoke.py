@@ -437,8 +437,7 @@ def health(ds, devices) -> None:
     from geomesa_tpu.metrics import (RESILIENCE_BREAKER_OPEN,
                                      RESILIENCE_DEGRADED,
                                      RESILIENCE_RETRIES, registry)
-    from geomesa_tpu.ops.pallas_kernels import GATES, on_tpu, \
-        pallas_health
+    from geomesa_tpu.ops.pallas_kernels import on_tpu, pallas_health
     st = ds._store(SCHEMA)
     tiers = st._lean_index().tier_counts()
     attr_tiers = st._lean_attr_index("name").tier_counts()
@@ -454,8 +453,6 @@ def health(ds, devices) -> None:
     ph = pallas_health()
     say(f"pallas {ph}")
     check(on_tpu(), "pallas: on_tpu() is false")
-    for kind, gate in GATES.items():
-        check(not gate.disabled, f"pallas gate {kind} is disabled")
     res = {k: registry.counter(k).count for k in
            (RESILIENCE_DEGRADED, RESILIENCE_RETRIES,
             RESILIENCE_BREAKER_OPEN)}

@@ -1,9 +1,8 @@
 """Check ``recompile-hazard``: ``jax.jit`` / ``pjit`` / ``shard_map``
 / ``pallas_call`` sites free of the silent-retrace traps.
 
-The warm-recompile budget (bench ``_obs_stanza`` + ``test_zz_obs``)
-only catches retraces the suite happens to EXECUTE; this check reads
-the hazard off the source:
+The warm-recompile budget (``test_zz_obs``) only catches retraces the
+suite happens to EXECUTE; this check reads the hazard off the source:
 
 * **unhashable static defaults** — a jit-wrapped def whose static
   argument has a list/dict/set default raises (or retraces) the first

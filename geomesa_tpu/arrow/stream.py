@@ -3,10 +3,8 @@
 The reference serves scan results as Arrow record batches encoded NEXT
 TO THE SCAN (``index/iterators/ArrowScan.scala``): per-tablet iterators
 emit delta-dictionary batches and no server-side SimpleFeature ever
-exists.  BENCH_r05 showed why that matters here: device scans cover
-~30M points/sec while materialized results flowed at ~88k features/sec
-— result construction (per-row feature ids, per-row Python objects),
-not the index, was the serving bottleneck.
+exists.  The same holds here: a row-wise result pays per-row feature
+ids and per-row Python objects that the index scan never does.
 
 This module is the TPU-native ArrowScan: hit positions (still sorted
 global row ids straight off the device scan) flow through

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache for the entry scripts.
 
-Only entry scripts call :func:`enable_compile_cache` (``chip_smoke.py``,
-``bench.py`` and the scale proofs); library modules never set a cache.
+Only entry scripts call :func:`enable_compile_cache` (``chip_smoke.py``
+and ``benchmark/run.py``); library modules never set a cache.
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
 directory is set here.  Otherwise the cache lives at the fixed
 ``<checkout>/.jax_cache`` (listed in ``.gitignore``): the path is part of

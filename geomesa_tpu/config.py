@@ -410,8 +410,7 @@ class PlanningProperties:
     retunes without restart."""
 
     #: sketch-fed estimation master switch: off makes the decider cost
-    #: strategies from whole-store stats / heuristics only (the PR 4
-    #: baseline — what the bench A/B compares against)
+    #: strategies from whole-store stats / heuristics only
     ESTIMATOR_ENABLED = SystemProperty(
         "geomesa.planning.estimator.enabled", True)
     #: live-row floor below which the sketch tier is skipped entirely:

@@ -1873,9 +1873,8 @@ class TpuDataStore:
         cardinality stays under ``geomesa.arrow.dictionary.threshold``).
 
         Byte-for-byte equal to encoding the row-wise
-        ``query_result().batch`` chunk-by-chunk — pinned by bench and
-        tests — at zero per-row Python object cost (the ~88k feats/sec
-        materialization wall of BENCH_r05).  Projections/reprojections
+        ``query_result().batch`` chunk-by-chunk — pinned by tests — at
+        zero per-row Python object cost.  Projections/reprojections
         (``properties``/``crs``) fall back to encoding the materialized
         row-wise batch.  Under multihost each process streams ITS local
         hit slice (per-shard delta streams; clients k-way merge via

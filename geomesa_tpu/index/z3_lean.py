@@ -42,8 +42,7 @@ bucket with the shared full-size sentinel generation
 
 **LSM lifecycle.**  Without maintenance, a streamed 1B build
 accumulates ~60 generations and every query/density call fans out over
-all of them (BENCH_r05: density_1b_ms 90.8s).  Two mechanisms bound
-that growth:
+all of them.  Two mechanisms bound that growth:
 
 * **Compaction** — :meth:`LeanZ3Index.compact`, a budgeted/resumable
   size-tiered K-way merge (device ``lax.sort`` for keys-tier runs,
@@ -996,7 +995,7 @@ class LeanZ3Index:
         #: opportunistic size-tiered compaction factor (0 = off; the
         #: explicit compact() maintenance call works either way)
         self.compaction_factor = int(compaction_factor or 0)
-        #: merge groups folded so far (observability; bench stanza)
+        #: merge groups folded so far (observability)
         self.compactions = 0
         #: sealed-generation density partials: spec → {gen_id: grid}.
         #: A sealed (demoted keys/host) generation's contribution to a

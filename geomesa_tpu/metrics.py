@@ -61,8 +61,8 @@ __all__ = ["MetricRegistry", "Timer", "Counter", "Gauge", "HistogramMetric",
 
 #: canonical counter names for the lean LSM lifecycle — compaction work
 #: (index/*_lean compact()) and the sealed-generation density-partial
-#: cache.  Named here so every index variant and the bench report read
-#: the same registry keys.
+#: cache.  Named here so every index variant and its readers use the
+#: same registry keys.
 LEAN_COMPACTION_MERGES = "lean.compaction.merges"
 LEAN_COMPACTION_ROWS = "lean.compaction.rows_merged"
 LEAN_DENSITY_CACHE_HITS = "lean.density.cache.hits"

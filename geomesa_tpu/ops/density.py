@@ -112,18 +112,13 @@ def density_grid_auto(x, y, weights, mask, env, width: int, height: int):
     """Dispatch: Pallas MXU one-hot histogram for small batches on TPU,
     sort-based segment sums for large batches or fine grids (one-hot work
     grows with n·G), XLA scatter elsewhere."""
-    from .pallas_kernels import GATES, density_grid_pallas, on_tpu
+    from .pallas_kernels import GATES, density_grid_pallas
 
-    if on_tpu():
+    if GATES["density"].choose():
         n = x.shape[0]
         if n >= _SORTED_MIN_N or n * width * height >= 6e10:
             return density_grid_sorted(x, y, weights, mask, env,
                                        width, height)
-        if GATES["density"].choose():
-            return density_grid_pallas(x, y, weights, mask, env,
-                                       width, height)
-        # disabled route = the XLA scatter path the tuning measurement
-        # actually compared against (pairing the decision with an
-        # unmeasured variant would let the measurement govern blind)
-        return density_grid(x, y, weights, mask, env, width, height)
+        return density_grid_pallas(x, y, weights, mask, env,
+                                   width, height)
     return density_grid(x, y, weights, mask, env, width, height)

@@ -46,46 +46,23 @@ class PallasGate:
     """Per-kernel Pallas routing: ``ok`` is None until the kernel first
     runs and True once it has.  A Mosaic failure is never caught here —
     it raises to the caller, so a run on the chip cannot silently serve
-    the XLA twin.
-
-    A kernel can be DISABLED BY MEASUREMENT: an explicit
-    :func:`apply_tuning` / :func:`record_tuning` call with a measured
-    pallas-vs-XLA win below 1.0 on this chip routes every later call
-    to the XLA path (nothing is applied at import)."""
+    the XLA twin."""
 
     def __init__(self, kind: str):
         self.kind = kind
         self.ok: bool | None = None
-        #: measured pallas-vs-XLA speedup (None = never measured here)
-        self.measured_win: float | None = None
-        #: True when the measurement says XLA is faster
-        self.disabled = False
 
     def choose(self, enabled: bool = True) -> bool:
-        """LOCAL routing decision for call sites that return lazy
-        arrays: True = take the pallas path.
-
-        Deliberately NOT agreed across processes: its only collective
-        call site (density_grid_auto inside the sharded density's
-        shard_map trace) would turn an agreement allgather into a
-        tracing-time collective.  Both density variants issue the
-        identical collective sequence (one psum of the same grid
-        shape), so a per-host divergent choice there is safe."""
-        return enabled and not self.disabled and on_tpu()
+        """Routing decision for call sites that return lazy arrays:
+        True = take the pallas path."""
+        return enabled and on_tpu()
 
     def run(self, pallas_thunk, xla_thunk, enabled: bool = True):
         """``enabled`` must be computed from process-invariant inputs
-        (global shapes, mesh size).  Multihost: the two variants are
+        (global shapes, mesh size): multihost, the two variants are
         different compiled programs entering the same mesh collectives,
-        so the ``disabled`` vote (set per host by an explicit tuning
-        call) is agreed before either is entered."""
-        attempt = enabled and on_tpu()
-        if attempt and jax.process_count() > 1:
-            from ..parallel.multihost import agreed_int
-            attempt = bool(agreed_int(int(not self.disabled), "min"))
-        else:
-            attempt = attempt and not self.disabled
-        if not attempt:
+        so every process must take the same one."""
+        if not (enabled and on_tpu()):
             return xla_thunk()
         out = pallas_thunk()
         self.ok = True
@@ -95,96 +72,6 @@ class PallasGate:
 #: one gate per integrated kernel; pallas_health reports them all
 GATES = {k: PallasGate(k)
          for k in ("z3_scan", "z2_scan", "hist1d", "density")}
-
-
-def _tuning_path() -> str:
-    import os
-    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".pallas_tuning.json")
-
-
-def load_tuning() -> dict:
-    import json
-    import os
-    try:
-        if os.path.exists(_tuning_path()):
-            with open(_tuning_path()) as f:
-                return json.load(f)
-    except Exception:
-        pass
-    return {}
-
-
-def device_kind() -> str:
-    """The accelerator model string tuning records key on (e.g.
-    ``'TPU v5 lite'``): a pallas-vs-XLA win is a property of ONE chip
-    generation — applying it on another chip is wrong in both
-    directions (ISSUE 3 satellite: a v5e measurement must not disable
-    kernels on a v6e, nor keep a slower kernel enabled there)."""
-    try:
-        return str(jax.devices()[0].device_kind)
-    except Exception:  # pragma: no cover — backend probing never fatal
-        return "unknown"
-
-
-def apply_tuning(wins: dict) -> None:
-    """Apply measured pallas-vs-XLA speedups to the gates: a win below
-    1.0 disables the kernel (loudly) — wiring a measured-slower kernel
-    into the hot path is a regression vector (round-4 VERDICT #6).
-
-    Entries are ``{kind: {"win": float, "device": str}}`` and apply
-    ONLY when their device string matches this process's chip; foreign-
-    device entries (and legacy un-attributed bare floats) are ignored —
-    a win measured on one chip must not gate another."""
-    import logging
-    dev = None   # resolved lazily: device_kind() initializes the jax
-    #              backend, which a no-entry import must never force
-    for kind, rec in wins.items():
-        gate = GATES.get(kind)
-        if gate is None:
-            continue
-        if not isinstance(rec, dict):
-            continue  # legacy bare-float entry: chip unknown — ignore
-        if dev is None:
-            dev = device_kind()
-        if str(rec.get("device")) != dev:
-            continue  # foreign chip's measurement
-        try:
-            win = float(rec.get("win"))
-        except (TypeError, ValueError):
-            continue  # hand-edited/foreign file: ignore, don't crash
-        gate.measured_win = win
-        slower = win < 1.0
-        if slower and not gate.disabled:
-            logging.getLogger("geomesa_tpu.pallas").warning(
-                "pallas %s measured %.2fx vs XLA on this chip — "
-                "disabled by measurement (.pallas_tuning.json)",
-                kind, win)
-        gate.disabled = slower
-
-
-def record_tuning(wins: dict) -> None:
-    """Persist measured speedups (bench.py calls this after timing each
-    kernel against its XLA twin on the real chip) and apply them to the
-    current process.  Each record carries THIS chip's device string;
-    same-device entries overwrite, foreign-device entries survive
-    untouched (per-chip merge semantics; atomic replace).  Legacy
-    un-attributed float entries for the re-measured kinds are dropped."""
-    import json
-    import os
-    dev = device_kind()
-    merged = load_tuning()
-    for k, v in wins.items():
-        if v is not None:
-            merged[k] = {"win": float(v), "device": dev}
-    path = _tuning_path()
-    try:
-        with open(path + ".tmp", "w") as f:
-            json.dump(merged, f, indent=1)
-        os.replace(path + ".tmp", path)
-    except OSError:
-        pass  # read-only checkouts still get the in-process effect
-    apply_tuning(merged)
 
 
 def _interpret() -> bool:
@@ -522,13 +409,8 @@ def hist1d_pallas(bins, weights, mask, n_bins: int):
 
 def pallas_health() -> dict:
     """Health snapshot: whether the Pallas paths are live on this
-    backend, which kernels have run, and any measurement that disabled
-    one."""
+    backend, and which kernels have run."""
     out = {"on_tpu": on_tpu()}
     for kind, gate in GATES.items():
         out[f"{kind}_ok"] = gate.ok
-        if gate.measured_win is not None:
-            out[f"{kind}_measured_win"] = round(gate.measured_win, 2)
-        if gate.disabled:
-            out[f"{kind}_disabled_by_measurement"] = True
     return out

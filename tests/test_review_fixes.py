@@ -177,54 +177,3 @@ def test_profile_context():
     assert len(t.times["phase.a"]) == 2
     assert t.total_ms("phase.a") >= 0
     assert "phase.a" in repr(t)
-
-
-def test_bench_compact_summary_bounded():
-    """The driver retains only the last ~2,000 stdout chars; the bench's
-    final line must parse and fit regardless of how the full record
-    grows (round-4 VERDICT weak #1)."""
-    import importlib.util
-    import json as _json
-    import os as _os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", _os.path.join(_os.path.dirname(__file__), "..",
-                               "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    big_scale = {"recorded_1b": {"rows": 10**9, "tiers": {"full": 1},
-                                 "query_warm_ms": list(range(100)),
-                                 "noise": ["x" * 100] * 50},
-                 "store_recorded": {"rows": 10**9,
-                                    "bulk": ["y" * 200] * 40},
-                 "store_live": {"rows": 8_000_000}}
-    full = {"metric": "m", "value": 1, "unit": "u", "vs_baseline": 1.0,
-            "extra": {"n_points": 1,
-                      "bbox_time_scan_features_per_sec": 1,
-                      "scan_points_covered_per_sec": 1, "scan_hits": 1,
-                      "batched_windows_per_sec": 1.0,
-                      "batched_window_hits": 1,
-                      "density_256x128_ms": 1.0,
-                      "chunked_append_keys_per_sec": 1,
-                      "chunked_total_rows": 1, "z2_or3_ms": 1.0,
-                      "z2_or3_hits": 1, "density_world_zprefix_ms": 1.0,
-                      "xz2_build_s": 1.0, "xz2_query_ms": 1.0,
-                      "xz2_candidates": 1, "knn25_4m_ms": 1.0,
-                      "tube40_4m_ms": 1.0,
-                      "pallas": {"measured_wins": {"density": 2.0},
-                                 "active": True},
-                      "scale": big_scale, "device": "TPU v5e"}}
-    line = _json.dumps(bench._compact_summary(full),
-                       separators=(",", ":"))
-    assert len(line) < 1900
-    parsed = _json.loads(line)
-    assert parsed["metric"] == "m"
-    # nested record noise must never ride along
-    assert "noise" not in line and "bulk" not in line
-
-    # the hard-trim fallback: force an oversized scalar field
-    full["extra"]["device"] = "d" * 5000
-    line2 = _json.dumps(bench._compact_summary(full),
-                        separators=(",", ":"))
-    assert len(line2) < 1900
-    assert _json.loads(line2)["extra"]["full_record"] == "BENCH_FULL.json"

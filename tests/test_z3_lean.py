@@ -1,6 +1,6 @@
-"""LeanZ3Index: tiered generational index (the 500M–1B single-chip
-scale path — scale_proof.py runs it on the real chip; this file keeps
-the logic under the fast CI loop).
+"""LeanZ3Index: tiered generational index (the lean profile's scale
+path; both benchmark cells run it on the chip, and this file keeps the
+logic under the fast CI loop).
 
 Round-4 coverage: sentinel-generation bucket padding does no extra
 dispatches (VERDICT #9), the full tier's device-side exact mask equals

@@ -103,7 +103,7 @@ def test_multi_chunk_delta_dictionary_roundtrip(ds):
 
 def test_byte_exact_vs_rowwise_encoding(ds):
     """The streamed IPC bytes are IDENTICAL to encoding the row-wise
-    materialized batch chunk-by-chunk (the bench gate's parity)."""
+    materialized batch chunk-by-chunk."""
     stream = ds.query_arrow("evt", ECQL, chunk_rows=4096,
                             dictionary_fields=("name",))
     got = stream.to_ipc_bytes()

@@ -209,35 +209,36 @@ def test_heat_gauges_publish_and_retire():
 
 
 def test_heat_overhead_proxy_on_warm_queries():
-    """Fast proxy for the 5% overhead budget: warm repeated queries
-    with heat tracking + tracing at defaults vs fully off.  CI timing
-    is noisy at ms scale, so the proxy bounds the tax at 15% on
-    min-of-9 — the bench stanza (`_heat_stanza`) holds the real ≤5%
-    budget at scale."""
+    """Heat tracking and tracing, on at their defaults, put no work on
+    the device: over the same warm windows they add no dispatch and no
+    compile against both off, and the answers match.  Their time cost
+    is inside the benchmark cells' end-to-end numbers, where both are
+    on by default."""
+    from geomesa_tpu.obs import compile_count
     ds = _mk_partitioned_store(name="hperf", slots=16384)
     idx = ds._store("hperf")._indexes["z3"]
     win = [([(-75.0, 40.0, -73.0, 42.0)], MS + 2 * i * DAY,
             MS + (2 * i + 2) * DAY) for i in range(4)]
 
-    def best_of(n):
-        best = float("inf")
-        for _ in range(n):
-            t0 = time.perf_counter()
-            idx.query_many(win)
-            best = min(best, time.perf_counter() - t0)
-        return best
+    def warm_run():
+        d0, c0 = idx.dispatch_count, compile_count()
+        out = [idx.query_many(win) for _ in range(3)]
+        return out, idx.dispatch_count - d0, compile_count() - c0
 
     idx.query_many(win)                    # warm/compile
-    on = best_of(12)
+    on, on_dispatches, on_compiles = warm_run()
     set_property("geomesa.obs.heat.enabled", False)
     set_property("geomesa.obs.enabled", False)
     try:
-        idx.query_many(win)                # settle
-        off = best_of(12)
+        off, off_dispatches, off_compiles = warm_run()
     finally:
         clear_property("geomesa.obs.heat.enabled")
         clear_property("geomesa.obs.enabled")
-    assert on <= off * 1.15, (on, off)
+    assert on_dispatches == off_dispatches > 0
+    assert on_compiles == off_compiles == 0
+    for a, b in zip(on, off):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)
 
 
 # -- write-path spans (tentpole b) -----------------------------------------

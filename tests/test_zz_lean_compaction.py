@@ -9,14 +9,13 @@ releasing merged runs' slack slots, cached density partials
 invalidating when sealed generations compact away, the ≥ 5× warm
 repeat density speedup, and the satellite regressions (sql_join
 multihost gate, string-None encoding, sharded attr slot burn,
-bench record fallback, partial-window density divergence bound).
+partial-window density divergence bound).
 
 Named ``test_zz_*`` deliberately: this is the heavyweight lifecycle
 suite (many-generation builds, device merges), so it runs at the END of
 the alphabetical tier-1 order, after the fast unit suites.
 """
 
-import json
 import time
 
 import numpy as np
@@ -462,22 +461,6 @@ def test_sharded_attr_append_reuses_padded_region():
     for probe in (0, 13, 29):
         np.testing.assert_array_equal(idx.query_equals(probe),
                                       np.array([probe]))
-
-
-def test_scale_stanza_skips_corrupt_record(tmp_path, monkeypatch):
-    import bench
-    here = tmp_path
-    (here / "STORE_SCALE_r05.json").write_text("{corrupt")
-    (here / "STORE_SCALE_r04.json").write_text(
-        json.dumps({"rows": 42}))
-    monkeypatch.setattr(bench.os.path, "dirname",
-                        lambda p: str(here))
-    monkeypatch.setenv("SCALE_LIVE_N", "0")
-    monkeypatch.setenv("STORE_SCALE_LIVE_N", "0")
-    out = bench._scale_stanza()
-    # the older round's parseable record wins; no error survives
-    assert out["store_recorded"] == {"rows": 42}
-    assert "store_recorded_error" not in out
 
 
 def test_partial_window_density_divergence_pinned():

@@ -421,6 +421,28 @@ def test_chaos_compaction_interrupt_resumes():
     assert merged == ["a", "b", "c"]
 
 
+def test_chaos_pyramid_build_interrupt_resumes():
+    from geomesa_tpu.index.pyramid import pyramid_spec
+    ds = _mk_store("rz_py")
+    idx = ds._store("rz_py")._lean_index()
+    sealed = [g.gen_id for g in idx.generations[:-1]]
+    assert len(sealed) > 1
+    want = ds.density_tile("rz_py", 0, 0, 0)   # the unbuilt store's answer
+    cache = idx._pyramid_cache.spec_cache(pyramid_spec(
+        config.DensityProperties.PYRAMID_BASE.to_int()))
+    config.set_property("geomesa.resilience.fault.points",
+                        "pyramid.build:1=error")
+    with pytest.raises(FaultInjected):
+        ds.build_pyramids("rz_py")
+    # interrupted BEFORE the first sweep: no pyramid cached
+    assert not any(gid in cache for gid in sealed)
+    _clear("geomesa.resilience.fault.points")
+    # the next pass builds every sealed generation, and tiles stay exact
+    assert ds.build_pyramids("rz_py") == len(sealed)
+    assert all(gid in cache for gid in sealed)
+    np.testing.assert_array_equal(ds.density_tile("rz_py", 0, 0, 0), want)
+
+
 def test_chaos_grid_covers_every_cataloged_point():
     """Every point in the FAULT_POINTS declaration has a chaos test in
     this module exercising it by name (the matrix stays total as
